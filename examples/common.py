@@ -15,7 +15,6 @@ convergence is assertable in CI.
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import numpy as np
@@ -218,17 +217,19 @@ def report_final(first_loss, last_loss, comm) -> int:
     return code
 
 
-def force_cpu_if_requested() -> None:
-    """Honor JAX_PLATFORMS even when a TPU plugin tries to override it
-    (must run before first jax backend use)."""
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
+def start_jax() -> None:
+    """An example's first touch of jax: place the compile cache
+    (pccl_tpu.utils.compile_cache) and say which devices this process now
+    holds. A TPU chip belongs to ONE process, so a second example started on
+    the same host must be given other devices (e.g. JAX_PLATFORMS=cpu)."""
+    import jax
 
-        try:
-            jax.config.update("jax_platforms", plat)
-        except Exception:  # noqa: BLE001 — backend already initialized
-            pass
+    from pccl_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    devs = jax.devices()
+    print(f"jax: platform={devs[0].platform} "
+          f"device_kind={devs[0].device_kind} count={len(devs)}", flush=True)
 
 
 def add_lr_schedule_args(ap: argparse.ArgumentParser) -> None:

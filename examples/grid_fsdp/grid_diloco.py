@@ -265,7 +265,7 @@ def main() -> int:
     g = args.peer_group
     assert 0 <= g < args.num_shards, "--peer-group must be < --num-shards"
 
-    common.force_cpu_if_requested()
+    common.start_jax()
     import jax
 
     from pccl_tpu.comm import SharedState, TensorInfo

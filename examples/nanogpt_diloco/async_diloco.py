@@ -39,7 +39,7 @@ def main() -> int:
     common.add_model_args(ap)
     args = ap.parse_args()
 
-    common.force_cpu_if_requested()
+    common.start_jax()
     import jax
     import jax.numpy as jnp
 

@@ -6,8 +6,8 @@ QuantizationAlgorithm, SharedStateSyncStrategy, Attribute, AsyncReduceHandle,
 ReduceDescriptor, plus the PcclError exception family.
 
 The native library loads lazily on first Communicator/MasterNode use, so
-importing this package never requires the C++ build (bench.py and pure-JAX
-users fall back cleanly).
+importing this package never requires the C++ build (pure-JAX users never
+touch it).
 """
 
 from .api import (  # noqa: F401

@@ -475,8 +475,10 @@ class TensorInfo:
         if lazy:
             def _materialize(_ctx):
                 # called from a native serving thread (ctypes re-acquires
-                # the GIL); one staging D2H, exactly once per sync window
-                np.copyto(host, np.asarray(ti._source))
+                # the GIL); one staging D2H, exactly once per sync window.
+                # Closes over `arr`, not `ti`: a ti -> callback -> ti cycle
+                # would keep the device array alive until a gc pass
+                np.copyto(host, np.asarray(arr))
 
             ti._materialize_cb = _native.MaterializeFn(_materialize)
         return ti
@@ -491,9 +493,12 @@ class TensorInfo:
             # lazy entry the sync never wrote to: the host buffer may be
             # unmaterialized garbage — the device array is authoritative
             return self._source
-        if self._source is not None and hasattr(self._source, "sharding"):
-            return jax.device_put(self.data, self._source.sharding)
-        return jax.device_put(self.data)
+        if not hasattr(self._source, "sharding"):
+            raise ValueError(
+                f"shared-state entry {self.name!r} was not built from a "
+                "jax.Array (from_jax / from_jax_device), so there is no "
+                "device to put its value on")
+        return jax.device_put(self.data, self._source.sharding)
 
     def _as_c(self, keepalive: list) -> _native.TensorInfoC:
         name_b = self.name.encode()

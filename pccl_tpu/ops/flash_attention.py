@@ -32,9 +32,11 @@ moves only Hkv-shaped K/V, which is the entire point of the architecture
 /root/reference/python/examples; grouped-query K/V shrinkage is a
 TPU-side design goal, not a port).
 
-On non-TPU backends `flash_attention` falls back to the jnp reference
-implementation (CI runs on a virtual CPU mesh); `interpret=True` forces the
-pallas interpreter for kernel-logic tests anywhere.
+`flash_attention` IS the kernel: it compiles for a TPU backend, runs under
+the pallas interpreter with `interpret=True` (kernel-logic tests anywhere),
+and raises on a backend or a shape it cannot take. The dense jnp twin is
+`reference_attention` / `dense_attention_with_lse`; a caller that wants it
+calls it by name.
 """
 
 from __future__ import annotations
@@ -56,8 +58,8 @@ _LANES = 128
 
 def reference_attention(q, k, v, causal: bool = True):
     """Dense jnp causal attention; q: [B, T, H, Dh], k/v: [B, T, Hkv, Dh]
-    (Hkv may divide H — GQA). One source of truth with the ring fallback:
-    softmax == exp(logits − lse)."""
+    (Hkv may divide H — GQA). One source of truth with ring attention's
+    dense twin: softmax == exp(logits − lse)."""
     return dense_attention_with_lse(q, k, v, causal)[0]
 
 
@@ -411,25 +413,40 @@ def default_blocks(T: int, Dh: int) -> tuple:
 
 
 def snap_block(b: int, T: int) -> int:
-    """Snap a block size DOWN to a divisor of T so mid-size T (1280,
-    2560, ...) stays on the kernel instead of silently falling back to the
-    dense O(T^2) path. A snapped block can drop below 128 (e.g. T=320 →
-    64) and still divide T: that tile underfills the MXU but the kernel
-    still runs and still beats the dense path's O(T²) memory — only when
-    NO power-of-two ≥ min(b, T)/… divides T does the caller's divisibility
-    check route to the fallback. Shared by flash_attention and the
-    ring-attention per-shard path."""
+    """Snap a block size DOWN (by halving) toward a divisor of T so mid-size
+    T (1280, 2560, ...) keeps a large tile. Whether the result is usable is
+    `check_blocks`'s call, not this function's. Shared by flash_attention
+    and the ring-attention per-shard path."""
     b = min(b, T)
     while b >= 128 and T % b:
         b //= 2
     return b
 
 
+def check_blocks(T: int, block_q: int, block_k: int, interpret: bool) -> None:
+    """Raise on a (T, block) combination the kernels cannot take. Compiled
+    for the chip, a block must be a whole number of 128-lane tiles: the
+    score tile is [block_q, block_k] and the lse row is stored at a dynamic
+    lane offset qi * block_q. The interpreter has no tiles, so there any
+    divisor of T runs (the CPU tests use 16..64)."""
+    if T % block_q or T % block_k:
+        raise ValueError(
+            f"flash attention: blocks ({block_q}, {block_k}) do not divide "
+            f"T={T}; pad the sequence or use reference_attention")
+    if not interpret and (block_q % _LANES or block_k % _LANES):
+        raise ValueError(
+            f"flash attention: blocks ({block_q}, {block_k}) for T={T} are "
+            f"not multiples of the {_LANES}-lane tile; T must be a multiple "
+            f"of {_LANES} on the chip")
+
+
 def dense_attention_with_lse(q, k, v, causal: bool = True):
-    """jnp twin of flash_attention_with_lse for non-TPU backends: returns
-    (out [B,T,H,Dh], lse [B,H,T] f32). Accepts GQA-shaped K/V ([B,T,Hkv,
-    Dh], Hkv dividing H) by repeating — the fallback optimizes for
-    correctness, the kernels for bytes. Plain jnp, so autodiff covers it."""
+    """jnp twin of flash_attention_with_lse — the reference the kernels are
+    checked against, and ring attention's per-shard op on a mesh that is
+    not made of TPUs: returns (out [B,T,H,Dh], lse [B,H,T] f32). Accepts
+    GQA-shaped K/V ([B,T,Hkv,Dh], Hkv dividing H) by repeating — the twin
+    optimizes for correctness, the kernels for bytes. Plain jnp, so
+    autodiff covers it."""
     H, Hkv = q.shape[2], k.shape[2]
     if Hkv != H:
         k = jnp.repeat(k, H // Hkv, axis=2)
@@ -499,19 +516,23 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 0,
     """Fused causal attention. q: [B, T, H, Dh], k/v: [B, T, Hkv, Dh]
     (Hkv == H for MHA, Hkv dividing H for GQA) → [B, T, H, Dh].
 
-    Uses the pallas kernels on TPU (or under `interpret`); falls back to
-    the dense jnp path elsewhere or when T doesn't tile. Differentiable:
-    forward AND backward are fused kernels (custom_vjp over the saved
-    log-sum-exp), so it drops into build_train_step and stays O(T) in
-    memory for long-context training."""
+    Runs the pallas kernels, compiled on a TPU backend or interpreted under
+    `interpret`; raises ValueError on any other backend and on a T the
+    blocks cannot tile (`check_blocks`) — there is no silent dense path.
+    Differentiable: forward AND backward are fused kernels (custom_vjp over
+    the saved log-sum-exp), so it drops into build_train_step and stays
+    O(T) in memory for long-context training."""
     B, T, H, Dh = q.shape
     if H % k.shape[2]:
         raise ValueError(f"GQA requires n_kv_head to divide n_head; got "
                          f"H={H}, Hkv={k.shape[2]}")
-    on_tpu = jax.default_backend() == "tpu"
+    if not interpret and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"flash_attention compiles for TPU only (backend is "
+            f"{jax.default_backend()!r}); pass interpret=True to run the "
+            f"kernel logic here, or call reference_attention")
     dbq, dbk = default_blocks(T, Dh)
     block_q = snap_block(block_q, T) if block_q else dbq
     block_k = snap_block(block_k, T) if block_k else dbk
-    if not (on_tpu or interpret) or T % block_q or T % block_k:
-        return reference_attention(q, k, v, causal=causal)
+    check_blocks(T, block_q, block_k, interpret)
     return _flash_diff(q, k, v, causal, block_q, block_k, interpret)

@@ -7,14 +7,15 @@ first-class requirement, so it lives here as a core op, not an example.
 Design (Liu et al., Ring Attention; implemented the XLA-collective way):
 Q/K/V are sequence-sharded over mesh axis `sp`. Each step, every device
 runs ONE per-shard attention of its resident Q block against the currently
-held K/V block — the fused flash-attention pallas kernels on TPU (forward
-and backward; no [Tl, Tl] tensor ever), the jnp twin elsewhere — and folds
+held K/V block — the fused flash-attention pallas kernels on a mesh of TPUs
+(forward and backward; no [Tl, Tl] tensor ever), the jnp twin on any other
+mesh — and folds
 the (out, log-sum-exp) pair into its accumulator, then rotates K/V one
 hop around the ring with `lax.ppermute` — after sp_size steps every Q block
 has seen every K/V block while K/V traffic only ever crosses neighboring
 devices (rides ICI, never DCN). XLA's latency-hiding scheduler overlaps the
 ppermute with the next step's kernel; peak per-device attention memory is
-one kernel tile on TPU (O(T²/n²) dense logits on the jnp fallback).
+one kernel tile on TPU (O(T²/n²) dense logits on the jnp twin).
 
 Causality uses GLOBAL positions (rank-offset iota), so the result is
 bit-equivalent in exact arithmetic to dense causal attention over the full
@@ -32,21 +33,25 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def _shard_attn_with_lse(q, k, v, blk_causal: bool):
-    """Per-shard attention returning (out, lse [B, H, Tl]) — the fused
-    pallas kernels on TPU (forward AND backward; no [Tl, Tl] tensor),
-    the jnp twin elsewhere. Blocks snapped to divisors of Tl."""
-    from .flash_attention import (default_blocks, dense_attention_with_lse,
+def _shard_attn_with_lse(q, k, v, blk_causal: bool, on_tpu: bool):
+    """Per-shard attention returning (out, lse [B, H, Tl]). On a mesh of
+    TPUs: the fused pallas kernels (forward AND backward; no [Tl, Tl]
+    tensor), which raise on a shard length they cannot tile. On any other
+    mesh the kernels cannot compile at all, so the jnp twin runs."""
+    from .flash_attention import (check_blocks, default_blocks,
+                                  dense_attention_with_lse,
                                   flash_attention_with_lse)
 
+    if not on_tpu:
+        return dense_attention_with_lse(q, k, v, blk_causal)
     Tl = q.shape[1]
     bq, bk = default_blocks(Tl, q.shape[-1])
-    if jax.default_backend() == "tpu" and Tl % bq == 0 and Tl % bk == 0:
-        return flash_attention_with_lse(q, k, v, blk_causal, bq, bk, False)
-    return dense_attention_with_lse(q, k, v, blk_causal)
+    check_blocks(Tl, bq, bk, interpret=False)
+    return flash_attention_with_lse(q, k, v, blk_causal, bq, bk, False)
 
 
-def _ring_attn_local(q, k, v, *, axis_name: str, causal: bool):
+def _ring_attn_local(q, k, v, *, axis_name: str, causal: bool,
+                     on_tpu: bool):
     """Per-device body under shard_map. q,k,v: [B, Tl, H, Dh] (local).
 
     The ring is UNROLLED over the (static) axis size: at step s the device
@@ -78,7 +83,8 @@ def _ring_attn_local(q, k, v, *, axis_name: str, causal: bool):
     lse = jnp.full((B, H, Tl), -jnp.inf, jnp.float32)
     kb, vb = k, v
     for s in range(n):
-        o_s, lse_s = _shard_attn_with_lse(q, kb, vb, causal and s == 0)
+        o_s, lse_s = _shard_attn_with_lse(q, kb, vb, causal and s == 0,
+                                          on_tpu)
         if causal and s > 0:
             visible = r >= s                       # whole-block visibility
             lse_s = jnp.where(visible, lse_s, -jnp.inf)
@@ -101,19 +107,18 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
     q,k,v: [B, T, H, Dh] with T sharded over mesh axis `axis` and B
     (optionally) over `batch_axis`. Returns [B, T, H, Dh], same layout.
     Composes inside an outer jit."""
-    import inspect
-
     ba = batch_axis if batch_axis and batch_axis in mesh.shape else None
     spec = P(ba, axis)
-    # pallas_call outputs carry no varying-mesh-axes annotation, which the
-    # replication checker refuses inside a checked shard_map; the kwarg
-    # was renamed check_rep -> check_vma across jax versions
-    params = inspect.signature(jax.shard_map).parameters
-    kw = ({"check_vma": False} if "check_vma" in params
-          else {"check_rep": False} if "check_rep" in params else {})
+    # the mesh says where this runs; the default backend does not (a CPU
+    # mesh on a TPU host must not be handed a Mosaic kernel)
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
+    # check_vma=False: pallas_call outputs carry no varying-mesh-axes
+    # annotation, which the checker refuses inside a checked shard_map
     fn = jax.shard_map(
-        partial(_ring_attn_local, axis_name=axis, causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **kw,
+        partial(_ring_attn_local, axis_name=axis, causal=causal,
+                on_tpu=on_tpu),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)
 

@@ -124,8 +124,12 @@ class Diloco:
         # tree canonical. Committed placement from step 0: uncommitted inputs
         # would retrace the jitted helpers once their outputs come back
         # committed — at 100M+ params each spurious retrace costs seconds.
+        # Everything this driver owns lives where the template's flat vector
+        # landed (self._outer_vec.sharding), never on the default device:
+        # several peers of one process each own a chip.
         self._outer_vec = self._flat_fn(params)
-        self._momentum_vec = jax.device_put(jnp.zeros((self.count,), jnp.float32))
+        self._momentum_vec = jnp.zeros((self.count,), jnp.float32,
+                                       device=self._outer_vec.sharding)
         # last in-flight apply output: overwriting the reused shm staging
         # buffer must wait for it (device_put on the CPU backend can alias
         # staged host memory zero-copy, so a pending apply may still read it)
@@ -226,10 +230,7 @@ class Diloco:
         wins = [jax.lax.slice_in_dim(delta, bounds[i], bounds[i + 1], axis=0)
                 for i in range(k)]
         for w in wins:
-            try:
-                w.copy_to_host_async()
-            except AttributeError:  # older jax: device_get blocks per window
-                break
+            w.copy_to_host_async()
         handles, views, failed = [], [], []
         for i, w in enumerate(wins):
             view = self._shm_stage[bounds[i]:bounds[i + 1]]
@@ -394,10 +395,15 @@ class Diloco:
         assert self.comm is not None
         st = self.shared_state()
         info = self.comm.sync_shared_state(st, strategy)
-        # adopt (possibly received) content
+        # adopt (possibly received) content. The last apply's output is the
+        # vector shared_state() just read back, so it has landed: drop the
+        # handle, or the replaced vector stays on the device (4 B/param)
+        # until the next outer step
+        self._applied = None
         self.step = int(self._ss_step[0])
-        self._momentum_vec = jnp.asarray(self._ss_mom)
-        self._outer_vec = jnp.asarray(self._ss_vec)
+        sharding = self._outer_vec.sharding
+        self._momentum_vec = jax.device_put(self._ss_mom, sharding)
+        self._outer_vec = jax.device_put(self._ss_vec, sharding)
         return info
 
 
@@ -450,7 +456,8 @@ class AsyncDiloco(Diloco):
         # read), so a cached tree would be a staleness hazard for a minor
         # win in a phase that already overlaps inner compute.
         new_vec, self._momentum_vec = self._apply_fn(
-            self._outer_vec, self._momentum_vec, jnp.asarray(self._async_out))
+            self._outer_vec, self._momentum_vec,
+            jax.device_put(self._async_out, self._outer_vec.sharding))
         self._outer_vec = self._applied = new_vec
         self.step += 1
 
@@ -471,7 +478,7 @@ class AsyncDiloco(Diloco):
         if self._async_out is None:
             self._async_out = np.empty(self.count, np.float32)
         # the pooled out buffer may still feed the apply just dispatched
-        # (jnp.asarray can alias it zero-copy on the CPU backend) — the
+        # (device_put can alias it zero-copy on the CPU backend) — the
         # background ring must not overwrite it until that apply lands
         if self._applied is not None:
             jax.block_until_ready(self._applied)

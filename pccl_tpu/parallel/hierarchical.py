@@ -32,7 +32,6 @@ from __future__ import annotations
 from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..comm import Communicator, DataType, QuantizationAlgorithm
@@ -125,5 +124,6 @@ class HierarchicalAllReduce:
         elif not host.flags["WRITEABLE"] or not host.flags["C_CONTIGUOUS"]:
             host = np.array(host, dtype=np.float32)  # ring reduces in place
         self._ring_avg(host)
-        out = self._codec.unflat(jnp.asarray(host))
+        # back to where the flat vector came from, not the default device
+        out = self._codec.unflat(jax.device_put(host, vec.sharding))
         return restore_shardings(out, self._shardings)

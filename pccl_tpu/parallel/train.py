@@ -45,7 +45,22 @@ def make_train_state(key, cfg, mesh, lr: float = 3e-4, schedule=None):
     params = init(key, cfg)
     tx = optax.adamw(schedule if schedule is not None else lr,
                      b1=0.9, b2=0.95, weight_decay=0.1)
-    opt_state = jax.jit(tx.init, out_shardings=None)(params)
+    # tx.init's zeros depend on no input, so with out_shardings left open
+    # they come back UNCOMMITTED on the default device: 8 B/param parked on
+    # device 0 whatever `mesh` is, and a second compile of the train step
+    # once its outputs return committed. Every params-shaped subtree of the
+    # state (the moments) takes the params' shardings; the rest (step
+    # counts) is replicated over the mesh.
+    params_def = jax.tree.structure(params)
+
+    def params_like(node):
+        return jax.tree.structure(node) == params_def
+
+    opt_sharding = jax.tree.map(
+        lambda node: param_sharding if params_like(node)
+        else mesh_lib.replicated(mesh),
+        jax.eval_shape(tx.init, params), is_leaf=params_like)
+    opt_state = jax.jit(tx.init, out_shardings=opt_sharding)(params)
     return params, tx, opt_state
 
 

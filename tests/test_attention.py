@@ -302,3 +302,26 @@ def test_flash_gqa_with_lse_pair():
         assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
+
+
+def test_flash_has_no_silent_dense_path():
+    """flash_attention IS the kernel: a backend it cannot compile for, a T
+    its blocks do not divide and (compiled) a block that is not a whole
+    number of 128-lane tiles all raise — none returns the dense reference."""
+    import pytest
+
+    from pccl_tpu.ops import flash_attention
+    from pccl_tpu.ops.flash_attention import check_blocks, default_blocks
+
+    q, k, v = _qkv(T=128)
+    with pytest.raises(ValueError, match="compiles for TPU only"):
+        flash_attention(q, k, v)                      # CPU backend, compiled
+    q, k, v = _qkv(T=96)
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
+    # what a TPU backend would be asked to compile
+    for T in (16, 64, 320):
+        with pytest.raises(ValueError, match="128-lane tile"):
+            check_blocks(T, *default_blocks(T, 64), interpret=False)
+    for T in (128, 384, 1024, 1280, 32768):
+        check_blocks(T, *default_blocks(T, 64), interpret=False)

@@ -201,6 +201,7 @@ def test_diloco_shared_state_joiner_catchup():
     np.testing.assert_allclose(adopted[1], np.full(8, 3.25))
 
 
+@needs_native
 def test_diloco_pipelined_windowed_reduce():
     """comm_windows>1 + shm_staging takes the pipelined path (per-window
     D2H overlapped with per-window tagged reduces); the averaged result

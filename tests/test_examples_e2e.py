@@ -28,6 +28,8 @@ def _peer_env() -> dict:
     # each peer process = one "slice" with a small virtual CPU mesh
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["JAX_PLATFORMS"] = "cpu"
+    # tests run without the persistent compile cache the examples enable
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return env
 
 

@@ -32,6 +32,8 @@ def _cell_env() -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["JAX_PLATFORMS"] = "cpu"
+    # tests run without the persistent compile cache the examples enable
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return env
 
 

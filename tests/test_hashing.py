@@ -141,6 +141,7 @@ def test_jax_simplehash_layout_independent(eight_devices):
     assert hashing.jax_simplehash(replicated) == h_host
 
 
+@needs_native
 def test_simplehash_tpu_numpy_vs_native():
     """The TPU-native hash (type 2) must be bit-identical between the
     numpy twin and the C++ core (pccltHashBuffer hash_type=2) across
@@ -185,6 +186,7 @@ def test_simplehash_tpu_device_parity():
             hashing.simplehash_tpu(host), (arr.dtype, arr.shape)
 
 
+@needs_native
 def test_simplehash_tpu_native_env_dispatch():
     """PCCLT_SS_HASH=simple-tpu must route content_hash to the new type
     (checked via pccltHashBuffer equivalence of types 0 vs 2 differing)."""
